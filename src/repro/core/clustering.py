@@ -106,10 +106,11 @@ def minibatch_kmeans_update(cents, counts, batch):
 
 
 def distributed_kmeans_step(x_local, cents, mesh_axis: str = "data"):
-    """One Lloyd step under shard_map: local partial sums + psum (multi-pod).
+    """One Lloyd step under ``jax.shard_map``: local partial sums + psum.
 
-    Call inside shard_map with x sharded over ``mesh_axis``; centroids are
-    replicated.  Returns updated centroids (replicated).
+    Call inside ``jax.shard_map`` (mesh entered with ``jax.set_mesh``) with
+    x sharded over ``mesh_axis``; centroids are replicated.  Returns
+    updated centroids (replicated).
     """
     k, d = cents.shape
     assign, _ = assign_clusters(x_local, cents)

@@ -9,6 +9,9 @@ ops.py (jit'd wrapper with platform dispatch) and ref.py (pure-jnp oracle):
 - flash_attention:   causal/SWA GQA prefill attention (serving the oracle LLM)
 - decode_attention:  single-token flash-decoding over a KV cache
 
-On non-TPU backends the ops fall back to the jnp reference; kernels are
-validated against refs in interpret mode (tests/kernels/).
+On TPU the ops always run the compiled kernel; on other backends they fall
+back to the jnp reference (or, asked for "pallas", interpret mode).  Kernels
+are validated against refs in interpret mode (tests/test_kernels.py) and
+compiled for a described TPU v5e at serving shapes
+(tests/test_tpu_compile.py).
 """

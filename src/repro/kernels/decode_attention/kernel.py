@@ -37,7 +37,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     q = q_ref[0, 0].astype(jnp.float32)  # (G, hd)
     k = k_ref[0, 0].astype(jnp.float32)  # (bl, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * scale  # (G, bl)
     pos = lj * block_l + lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -76,22 +76,28 @@ def decode_attention_pallas(q, k, v, lengths, *, block_l: int = 256,
 
     kernel = functools.partial(_decode_kernel, scale=scale, block_l=bl,
                                n_l=n_l, G=G)
-    out = pl.pallas_call(
-        kernel,
+    # lengths (B,) is scalar-prefetched into SMEM; each program reads its
+    # own sequence's entry
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KV, n_l),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, c, lj: (b,)),
-            pl.BlockSpec((1, 1, G, hd), lambda b, c, lj: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, bl, hd), lambda b, c, lj: (b, c, lj, 0)),
-            pl.BlockSpec((1, 1, bl, hd), lambda b, c, lj: (b, c, lj, 0)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, c, lj, ln: (b, c, 0, 0)),
+            pl.BlockSpec((1, 1, bl, hd), lambda b, c, lj, ln: (b, c, lj, 0)),
+            pl.BlockSpec((1, 1, bl, hd), lambda b, c, lj, ln: (b, c, lj, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, c, lj: (b, c, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, hd),
+                               lambda b, c, lj, ln: (b, c, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, hd), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
     )(lengths.astype(jnp.int32), qr, k, v)
     return out.reshape(B, H, hd)

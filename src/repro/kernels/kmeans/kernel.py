@@ -22,14 +22,17 @@ def _assign_kernel(x_ref, c_ref, csq_ref, assign_ref, dmin_ref):
     c = c_ref[...].astype(jnp.float32)  # (K, D)
     csq = csq_ref[...]  # (1, K)
     xsq = jnp.sum(x * x, axis=-1, keepdims=True)  # (BN, 1)
+    # HIGHEST: an f32 dot at the MXU's default precision rounds its inputs
+    # to bf16, which moves distances by ~1e-3 on unit-norm embeddings
     scores = lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+                             precision=lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)  # (BN, K)
     d = jnp.maximum(xsq - 2.0 * scores + csq, 0.0)
-    dmin = jnp.min(d, axis=-1)
+    dmin = jnp.min(d, axis=-1, keepdims=True)  # (BN, 1)
     k = d.shape[-1]
     iota = lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    amin = jnp.min(jnp.where(d == dmin[:, None], iota, k), axis=-1)
-    assign_ref[...] = amin.astype(jnp.int32)
+    assign_ref[...] = jnp.min(jnp.where(d == dmin, iota, k), axis=-1,
+                              keepdims=True)
     dmin_ref[...] = dmin
 
 
@@ -51,14 +54,16 @@ def assign_clusters_pallas(x, cents, block_n: int = 256, interpret: bool = False
             pl.BlockSpec((k, d), lambda i: (0, 0)),
             pl.BlockSpec((1, k), lambda i: (0, 0)),
         ],
+        # (BN, 1) column blocks: a 2-D block whose last dim is the array's
+        # whole last dim is a layout Mosaic accepts for any BN % 8 == 0
         out_specs=[
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), jnp.int32),
-            jax.ShapeDtypeStruct((n_pad,), jnp.float32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x, cents, csq)
-    return assign[:n], dmin[:n]
+    return assign[:n, 0], dmin[:n, 0]

@@ -13,7 +13,8 @@ Two entry points:
   in ONE kernel launch.  Rows are packed per cluster into block_n-aligned
   segments; a scalar-prefetched ``block_seg`` table maps each row block to
   its cluster, and the BlockSpec index maps use it to DMA that cluster's
-  sample tile, label tile, and bandwidth — the grouped-matmul pattern.
+  sample and label tiles — the grouped-matmul pattern.  The per-cluster
+  bandwidths are scalar-prefetched too and read from SMEM.
 """
 from __future__ import annotations
 
@@ -27,7 +28,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _simvote_kernel(x_ref, s_ref, y_ref, inv2t2_ref, num_ref, den_ref):
+def _simvote_accumulate(x_ref, s_ref, y_ref, inv2t2, num_ref, den_ref):
+    """Add one (BN x BM) tile's weights into the row accumulators."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -38,17 +40,24 @@ def _simvote_kernel(x_ref, s_ref, y_ref, inv2t2_ref, num_ref, den_ref):
     x = x_ref[...].astype(jnp.float32)  # (BN, D)
     s = s_ref[...].astype(jnp.float32)  # (BM, D)
     y = y_ref[...].astype(jnp.float32)  # (1, BM); 0/1 labels, -1 = pad
-    inv2t2 = inv2t2_ref[0, 0]
     xsq = jnp.sum(x * x, axis=-1, keepdims=True)
     ssq = jnp.sum(s * s, axis=-1)[None, :]
+    # f32 throughout: HIGHEST keeps the MXU from rounding the distance
+    # dot's inputs to bf16, and both sums run on the VPU
     d2 = jnp.maximum(xsq - 2.0 * lax.dot_general(
-        x, s, (((1,), (1,)), ((), ())),
+        x, s, (((1,), (1,)), ((), ())), precision=lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32) + ssq, 0.0)  # (BN, BM)
     w = jnp.exp(-d2 * inv2t2)
     valid = (y >= 0.0)
     w = jnp.where(valid, w, 0.0)
-    num_ref[...] += w @ jnp.where(valid, y, 0.0).reshape(-1, 1)  # (BN,1)
+    num_ref[...] += jnp.sum(w * jnp.where(valid, y, 0.0), axis=-1,
+                            keepdims=True)  # (BN, 1)
     den_ref[...] += jnp.sum(w, axis=-1, keepdims=True)
+
+
+def _simvote_kernel(x_ref, s_ref, y_ref, inv2t2_ref, num_ref, den_ref):
+    _simvote_accumulate(x_ref, s_ref, y_ref, inv2t2_ref[0, 0], num_ref,
+                        den_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_m", "interpret"))
@@ -90,13 +99,14 @@ def simvote_scores_pallas(x, s, y, tau, block_n: int = 256,
     return (num[:n, 0] / jnp.maximum(den[:n, 0], 1e-30))
 
 
-def _simvote_segmented_kernel(seg_ref, x_ref, s_ref, y_ref, inv2t2_ref,
+def _simvote_segmented_kernel(seg_ref, inv2t2_ref, x_ref, s_ref, y_ref,
                               num_ref, den_ref):
-    # seg_ref is the scalar-prefetched block->cluster table; the index maps
-    # already routed the right cluster tiles here, so the body is the plain
-    # single-cluster accumulator.
-    del seg_ref
-    _simvote_kernel(x_ref, s_ref, y_ref, inv2t2_ref, num_ref, den_ref)
+    # seg_ref (block -> cluster) and inv2t2_ref (per-cluster bandwidth) are
+    # scalar-prefetched into SMEM; the index maps already routed the
+    # cluster's sample tiles here, so the body is the single-cluster
+    # accumulator with that cluster's bandwidth.
+    inv2t2 = inv2t2_ref[seg_ref[pl.program_id(0)]]
+    _simvote_accumulate(x_ref, s_ref, y_ref, inv2t2, num_ref, den_ref)
 
 
 def simvote_scores_segmented_pallas(x, counts, s_pad, y_pad, taus,
@@ -139,22 +149,21 @@ def simvote_scores_segmented_pallas(x, counts, s_pad, y_pad, taus,
                      ((0, 0), (0, m_pad - m), (0, 0))).reshape(c * m_pad, d)
     y_flat = jnp.pad(y_pad.astype(jnp.float32), ((0, 0), (0, m_pad - m)),
                      constant_values=-1.0).reshape(1, c * m_pad)
-    inv2t2 = (1.0 / (2.0 * jnp.asarray(taus, jnp.float32).reshape(c, 1) ** 2))
+    inv2t2 = 1.0 / (2.0 * jnp.asarray(taus, jnp.float32) ** 2)  # (C,)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nb_total, mblocks),
         in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, j, seg: (i, 0)),
+            pl.BlockSpec((block_n, d), lambda i, j, seg, t: (i, 0)),
             pl.BlockSpec((block_m, d),
-                         lambda i, j, seg: (seg[i] * mblocks + j, 0)),
+                         lambda i, j, seg, t: (seg[i] * mblocks + j, 0)),
             pl.BlockSpec((1, block_m),
-                         lambda i, j, seg: (0, seg[i] * mblocks + j)),
-            pl.BlockSpec((1, 1), lambda i, j, seg: (seg[i], 0)),
+                         lambda i, j, seg, t: (0, seg[i] * mblocks + j)),
         ],
         out_specs=[
-            pl.BlockSpec((block_n, 1), lambda i, j, seg: (i, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, j, seg: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i, j, seg, t: (i, 0)),
+            pl.BlockSpec((block_n, 1), lambda i, j, seg, t: (i, 0)),
         ],
     )
     num, den = pl.pallas_call(
@@ -165,6 +174,6 @@ def simvote_scores_segmented_pallas(x, counts, s_pad, y_pad, taus,
             jax.ShapeDtypeStruct((nb_total * block_n, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(jnp.asarray(block_seg), x_pad, s_flat, y_flat, inv2t2)
+    )(jnp.asarray(block_seg), inv2t2, x_pad, s_flat, y_flat)
     gather = jnp.asarray(row_idx)
     return num[gather, 0] / jnp.maximum(den[gather, 0], 1e-30)

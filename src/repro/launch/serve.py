@@ -1,10 +1,14 @@
 """Production serving driver: the CSV data plane + oracle model plane.
 
-    PYTHONPATH=src python -m repro.launch.serve --arch llama3.1-8b --smoke
+    PYTHONPATH=src python -m repro.launch.serve --service 4 --n 4000
 
-Boots the backbone on the mesh, the embedding encoder, and answers
-semantic-filter requests through the CSV driver with the batched engine.
-On restart, the oracle call-cache checkpoint avoids re-invoking the LLM.
+Boots the oracle backbone (``--arch``, default ``qwen1.5-0.5b``) and the
+``e5-large`` embedding encoder at their published widths with random
+weights, and answers semantic-filter requests through the CSV driver with
+the batched engine.  ``--smoke`` swaps both for their tiny presets.  The
+run needs a TPU; ``JAX_PLATFORMS=cpu`` runs it on the CPU on purpose
+(tests, CI — Pallas kernels then run in interpret mode).  On restart, the
+oracle call-cache checkpoint avoids re-invoking the LLM.
 
 ``--service K`` switches to the concurrent front end (repro.service): K
 predicates become K ModelOracles over one shared engine, submitted
@@ -20,7 +24,6 @@ import json
 import pathlib
 
 import jax
-import numpy as np
 
 from repro.configs import get_config, smoke_config
 from repro.core import CSVConfig, SemanticTable
@@ -28,6 +31,7 @@ from repro.core.oracle import ModelOracle
 from repro.core.operators import accuracy_f1
 from repro.data import make_dataset, HashTokenizer
 from repro.embeddings import EmbeddingModel
+from repro.launch.runtime import enable_compile_cache, require_tpu
 from repro.models import lm
 from repro.obs import (FlightRecorder, HealthMonitor, LogAlertSink,
                        MetricsRegistry, StatusHub, Tracer, default_rules,
@@ -67,6 +71,28 @@ def export_trace(trace_dir: str, tracer: Tracer, registry: MetricsRegistry,
           f"(spans.jsonl, trace.json, ticks.jsonl, metrics.prom, "
           f"metrics.json)")
     return files
+
+
+def build_stack(arch: str, smoke: bool, n: int, attn_impl: str = None,
+                max_batch: int = 64):
+    """Oracle engine, tokenizer, table and its embeddings for one run.
+
+    Full published widths unless ``smoke``: the ``arch`` backbone behind a
+    ``ServingEngine`` and the ``e5-large`` encoder embedding ``n`` rows of
+    the synthetic ``imdb_review`` table.  Weights are random from seed 0.
+    """
+    cfg = smoke_config(arch) if smoke else get_config(arch)
+    if attn_impl:
+        cfg = cfg.replace(attn_impl=attn_impl)
+    params = lm.init_params(cfg, jax.random.key(0))
+    engine = ServingEngine(cfg, params, max_batch=max_batch)
+    tok = HashTokenizer(cfg.vocab_size)
+    ds = make_dataset("imdb_review", n=n, seed=0)
+    if smoke:
+        encoder = EmbeddingModel(smoke_config("e5-large"), max_len=32)
+    else:
+        encoder = EmbeddingModel(get_config("e5-large"))
+    return engine, tok, ds, encoder.encode(ds.texts)
 
 
 def serve_concurrent(engine, tok, ds, embeddings, k: int, state_dir: str,
@@ -151,8 +177,11 @@ def serve_concurrent(engine, tok, ds, embeddings, k: int, state_dir: str,
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="llama3.1-8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--arch", default="qwen1.5-0.5b",
+                    help="oracle backbone (default fits one TPU v5e)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="tiny presets of the backbone and the encoder "
+                         "instead of their published widths")
     ap.add_argument("--n", type=int, default=400)
     ap.add_argument("--predicate", default="the review is positive")
     ap.add_argument("--vote", default="csv", choices=["csv", "csv-sim"])
@@ -201,6 +230,8 @@ def main():
                     help="raise after the run completes (CI: exercises "
                          "the flight recorder's crash path)")
     args = ap.parse_args()
+    require_tpu("serve")
+    enable_compile_cache()
 
     registry = MetricsRegistry()
     tracer = None
@@ -226,16 +257,9 @@ def main():
         start_metrics_server(registry, args.metrics_port,
                              host=args.metrics_host, hub=hub)
 
-    cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
-    if args.attn_impl:
-        cfg = cfg.replace(attn_impl=args.attn_impl)
-    params = lm.init_params(cfg, jax.random.key(0))
-    engine = ServingEngine(cfg, params, max_batch=args.max_batch)
-    tok = HashTokenizer(cfg.vocab_size)
-
-    ds = make_dataset("imdb_review", n=args.n, seed=0)
-    encoder = EmbeddingModel(smoke_config("e5-large"), max_len=32)
-    embeddings = encoder.encode(ds.texts)
+    engine, tok, ds, embeddings = build_stack(
+        args.arch, args.smoke, args.n, attn_impl=args.attn_impl,
+        max_batch=args.max_batch)
 
     if args.service > 0:
         sess, results = serve_concurrent(

@@ -2,6 +2,9 @@
 
     PYTHONPATH=src python -m repro.launch.watch --n 400 --queries 3
 
+Like ``serve``, it needs a TPU unless ``JAX_PLATFORMS=cpu`` asks for the
+CPU on purpose.
+
 Replays a deterministic stream against K standing queries over one
 session (docs/streaming.md): rows arrive per tick under a per-source
 rate budget, each tick coalesced-appends them and re-votes only the
@@ -26,6 +29,7 @@ import numpy as np
 from repro.api import ExecutionPolicy, Session
 from repro.core import SyntheticOracle
 from repro.data import make_dataset
+from repro.launch.runtime import enable_compile_cache, require_tpu
 from repro.obs import (FlightRecorder, HealthMonitor, LogAlertSink,
                        MetricsRegistry, StatusHub, Tracer, default_rules,
                        set_flight_recorder, set_monitor, set_tracer)
@@ -134,6 +138,8 @@ def main():
                          "or critical health alert")
     ap.add_argument("--trace-dir", default=None, metavar="DIR")
     args = ap.parse_args()
+    require_tpu("watch")
+    enable_compile_cache()
 
     registry = MetricsRegistry()
     tracer = None

@@ -308,8 +308,8 @@ def attention_flash(cfg: ModelConfig, p, x, *, causal=True, window=None,
                     positions=None):
     """Pallas flash-attention kernel on the prefill/forward hot path.
 
-    ``attn_impl="flash"`` runs ``repro.kernels.flash_attention`` (interpret
-    mode off-TPU, so the serving path is testable on CPU);
+    ``attn_impl="flash"`` runs ``repro.kernels.flash_attention`` (compiled
+    on TPU; interpret mode off-TPU, so the serving path is testable on CPU);
     ``attn_impl="flash-ref"`` runs its jnp oracle.  Mask positions are
     sequence-local 0..S-1 (same assumption as the chunked tri/rect
     schedules); ``positions`` feeds RoPE only.
@@ -342,10 +342,13 @@ def attention_apply(cfg: ModelConfig, p, x, *, causal=True, window=None,
         return attention_plain(cfg, p, x, causal=causal, window=window,
                                positions=positions, kv_x=kv_x)
     if impl in ("flash", "flash-ref"):
-        if S % min(128, S) == 0:  # kernel block divisibility
-            return attention_flash(cfg, p, x, causal=causal, window=window,
-                                   positions=positions)
-        return attention_plain(cfg, p, x, causal=causal, window=window,
+        # the kernel tiles S by min(128, S); the engine's power-of-two
+        # buckets (>= 32) always divide.  Never swap in another path: the
+        # caller asked for the kernel.
+        if S % min(128, S):
+            raise ValueError(f"attn_impl={impl!r} needs the sequence length "
+                             f"to be a multiple of 128 or below it; got {S}")
+        return attention_flash(cfg, p, x, causal=causal, window=window,
                                positions=positions)
     if impl == "plain" or (impl == "auto" and S <= 4096 and window is None):
         return attention_plain(cfg, p, x, causal=causal, window=window,

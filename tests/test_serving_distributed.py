@@ -1,4 +1,5 @@
 """Serving engine, batcher, sharding rules, and an 8-device shard_map check."""
+import os
 import subprocess
 import sys
 import textwrap
@@ -93,7 +94,6 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core.clustering import distributed_kmeans_step
     from repro.kernels.kmeans.ref import assign_clusters_ref
 
@@ -101,10 +101,11 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
     x = jax.random.normal(jax.random.key(0), (800, 16), jnp.float32)
     c = jax.random.normal(jax.random.key(1), (4, 16), jnp.float32)
 
-    step = shard_map(partial(distributed_kmeans_step, mesh_axis="data"),
-                     mesh=mesh, in_specs=(P("data"), P(None, None)),
-                     out_specs=P(None, None))
-    c_dist = step(x, c)
+    with jax.set_mesh(mesh):
+        step = jax.jit(jax.shard_map(
+            partial(distributed_kmeans_step, mesh_axis="data"),
+            in_specs=(P("data"), P(None, None)), out_specs=P(None, None)))
+        c_dist = step(x, c)
     # single-device oracle
     a, _ = assign_clusters_ref(x, c)
     a = np.asarray(a)
@@ -116,9 +117,15 @@ MULTIDEV_SCRIPT = textwrap.dedent("""
 
 
 def test_distributed_kmeans_shard_map():
-    """8 fake devices in a subprocess (keeps this process at 1 device)."""
+    """8 fake CPU devices in a subprocess (keeps this process at 1 device).
+
+    The child is pinned to the CPU explicitly, so it never loads the TPU
+    library (which one process at a time may hold).
+    """
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
     r = subprocess.run([sys.executable, "-c", MULTIDEV_SCRIPT],
                        capture_output=True, text=True, timeout=600,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={"PYTHONPATH": src, "JAX_PLATFORMS": "cpu",
+                            "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
+                            "HOME": os.environ.get("HOME", "")})
     assert "DISTRIBUTED_KMEANS_OK" in r.stdout, r.stderr[-2000:]
