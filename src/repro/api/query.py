@@ -447,33 +447,38 @@ class FilterQuery(Query):
         t0 = monotonic()
         with tr.span("query", kind="query", query="filter",
                      table=self.handle.name, method=pol.method) as qsp:
-            # sight every leaf oracle as having touched this table EVEN when
-            # reuse is off: TableHandle.update() must be able to invalidate
-            # stale per-id oracle memos regardless of the policy the oracle
-            # was used under.  Sightings are weak — they never extend oracle
-            # lifetimes
-            for oracle in self._oracles():
-                self.session.memo.note_sighting(self.handle.name, oracle)
-            # proxy spend is tracked separately (session.proxy_stats):
-            # proxy calls are the cheap cascade model, not LLM-oracle spend
-            proxy_snap = _snapshot([self.proxy]
-                                   if self.proxy is not None else [])
-            if pol.is_baseline:
-                name = self.expr.leaves()[0].name
-                n = len(self.handle)
-                ests = [NodeEstimate(name=name, est_live_in=float(n),
-                                     est_calls=float(n), selectivity=None)]
+            # plan first: _prepare absorbs any fresh pilot spend into the
+            # session aggregate, so the snapshot below covers the cascade.
+            # A cascade's pilot waits on the oracle, so it stays outside
+            # the work span
+            prepared = None if pol.is_baseline else self._prepare(pol)
+            with tr.work("repro.query.prepare"):
+                # sight every leaf oracle as having touched this table EVEN
+                # when reuse is off: TableHandle.update() must be able to
+                # invalidate stale per-id oracle memos regardless of the
+                # policy the oracle was used under.  Sightings are weak —
+                # they never extend oracle lifetimes
+                for oracle in self._oracles():
+                    self.session.memo.note_sighting(self.handle.name, oracle)
+                # proxy spend is tracked separately (session.proxy_stats):
+                # proxy calls are the cheap cascade model, not LLM spend
+                proxy_snap = _snapshot([self.proxy]
+                                       if self.proxy is not None else [])
+                if pol.is_baseline:
+                    n = len(self.handle)
+                    ests = [NodeEstimate(name=self.expr.leaves()[0].name,
+                                         est_live_in=float(n),
+                                         est_calls=float(n),
+                                         selectivity=None)]
+                else:
+                    ests = node_estimates(prepared.physical,
+                                          len(self.handle),
+                                          prepared.pilot_stats,
+                                          pol.to_csv_config())
                 snap = _snapshot(self._oracles())
+            if pol.is_baseline:
                 raw = self._run_baseline(pol, self.expr.leaves()[0].oracle)
             else:
-                # plan first: _prepare absorbs any fresh pilot spend into
-                # the session aggregate, so the snapshot below covers the
-                # cascade
-                prepared = self._prepare(pol)
-                ests = node_estimates(prepared.physical, len(self.handle),
-                                      prepared.pilot_stats,
-                                      pol.to_csv_config())
-                snap = _snapshot(self._oracles())
                 raw = self._executor(pol).run(self.expr, prepared=prepared)
             for oracle, before in snap:
                 self.session._absorb(oracle.stats.delta(before))

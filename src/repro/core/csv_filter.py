@@ -215,11 +215,12 @@ def _recluster_or_fallback(emb, oracle, cfg, pending, depth, result, decided):
             decided[pending] = True
             sp.set(outcome="small_fallback")
             return [], len(pending), monotonic() - t_rc
-        _, sub_assign, _ = kmeans(key, jnp.asarray(emb[pending]), k,
-                                  max_iters=cfg.kmeans_iters)
-        sub_assign = np.asarray(sub_assign)
-        queue = [pending[sub_assign == c] for c in range(k)]
-        queue = [c for c in queue if len(c)]
+        with tr.work("repro.csv.kmeans", n=int(len(pending))):
+            _, sub_assign, _ = kmeans(key, jnp.asarray(emb[pending]), k,
+                                      max_iters=cfg.kmeans_iters)
+            sub_assign = np.asarray(sub_assign)
+            queue = [pending[sub_assign == c] for c in range(k)]
+            queue = [c for c in queue if len(c)]
         sp.set(outcome="recluster", n_children=len(queue))
         return queue, 0, monotonic() - t_rc
 
@@ -237,7 +238,7 @@ def _run_round_executor(emb, oracle, cfg, rng, xi, result, decided,
         with tr.span("round", kind="round", depth=depth,
                      n_clusters=len(queue), executor="round") as rsp:
             t_round = monotonic()
-            with tr.span("plan", kind="plan"):
+            with tr.work("repro.csv.plan", kind="plan"):
                 plan = plan_round(queue, rng, xi, cfg, depth)
             n_waves = max(1, min(int(cfg.pipeline_depth),
                                  len(plan.clusters)))
@@ -272,15 +273,15 @@ def _run_round_executor(emb, oracle, cfg, rng, xi, result, decided,
                         flat_labels = handles[k].result()
                         osp.set(batch=int(len(flat_labels)))
                     oracle_batches.append(int(len(flat_labels)))
-                    offsets = np.cumsum([cp.n_sample for cp in wave])[:-1]
-                    labels_by_cluster = np.split(flat_labels, offsets)
 
-                    for cp, labels in zip(wave, labels_by_cluster):
-                        result[cp.sample_ids] = labels
-                        decided[cp.sample_ids] = True
-
-                    with tr.span("vote", kind="vote", wave=k,
+                    with tr.work("repro.csv.vote", kind="vote", wave=k,
                                  n_clusters=len(wave)):
+                        offsets = np.cumsum(
+                            [cp.n_sample for cp in wave])[:-1]
+                        labels_by_cluster = np.split(flat_labels, offsets)
+                        for cp, labels in zip(wave, labels_by_cluster):
+                            result[cp.sample_ids] = labels
+                            decided[cp.sample_ids] = True
                         votes = _vote_wave(wave, labels_by_cluster, emb,
                                            cfg, lb, ub)
                         for i, cp in enumerate(wave):

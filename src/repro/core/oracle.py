@@ -21,6 +21,8 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro.obs.trace import get_tracer
+
 
 @dataclasses.dataclass
 class OracleStats:
@@ -146,10 +148,13 @@ class BaseOracle:
         return out
 
     def __call__(self, ids) -> np.ndarray:
-        out, missing, missing_pos = self._memo_split(ids)
+        tr = get_tracer()
+        with tr.work("repro.oracle.memo_split"):
+            out, missing, missing_pos = self._memo_split(ids)
         if missing:
             labels = self._evaluate(np.asarray(missing, dtype=np.int64))
-            out = self._memo_commit(out, missing, missing_pos, labels)
+            with tr.work("repro.oracle.commit"):
+                out = self._memo_commit(out, missing, missing_pos, labels)
         return out
 
     # --- persistence (fault tolerance / §3.1 update cache) ---
@@ -266,9 +271,11 @@ class ModelOracle(BaseOracle):
         # device.  Per-prompt (B, 2) token ids — the same einsum shape the
         # packed cross-oracle wave uses, so packed and per-oracle dispatch
         # produce bit-identical logits.
-        pair = self.engine.first_token_logits(
-            self.pack_prompts(ids), token_ids=self.pack_token_ids(len(ids)))
-        return self.pack_labels(pair)
+        with get_tracer().work("repro.oracle.tokenize", prompts=len(ids)):
+            prompts = self.pack_prompts(ids)
+            tids = self.pack_token_ids(len(ids))
+        return self.pack_labels(
+            self.engine.first_token_logits(prompts, token_ids=tids))
 
     def _tokens_of(self, ids):
         return int(sum(len(self._prompt_ids(int(i))) for i in ids))
@@ -316,6 +323,7 @@ def evaluate_packed(requests, pack: bool = True):
     decision tokens spent) and ``truncated`` (prompts the engine batcher
     left-truncated during this call).
     """
+    tr = get_tracer()
     outcomes: list = [None] * len(requests)
     info = {"tokens": 0, "truncated": 0}
     remaining = list(enumerate(requests))
@@ -339,20 +347,22 @@ def evaluate_packed(requests, pack: bool = True):
                 except BaseException as e:
                     outcomes[idx] = e
                 continue
-            split = oracle._memo_split(ids)
+            with tr.work("repro.oracle.memo_split"):
+                split = oracle._memo_split(ids)
             engines[id(engine)] = engine
             packable.setdefault(id(engine), []).append((idx, oracle, split))
         for ekey, group in packable.items():
             engine = engines[ekey]
             prompts, tok_rows = [], []
-            for _, oracle, (_, missing, _) in group:
-                prompts.extend(oracle.pack_prompts(missing))
-                tok_rows.append(oracle.pack_token_ids(len(missing)))
+            with tr.work("repro.oracle.tokenize", requests=len(group)):
+                for _, oracle, (_, missing, _) in group:
+                    prompts.extend(oracle.pack_prompts(missing))
+                    tok_rows.append(oracle.pack_token_ids(len(missing)))
+                tids = np.concatenate(tok_rows)
             if prompts:
                 trunc0 = engine.batcher.stats["truncated_prompts"]
                 try:
-                    pair = engine.first_token_logits(
-                        prompts, token_ids=np.concatenate(tok_rows))
+                    pair = engine.first_token_logits(prompts, token_ids=tids)
                 except BaseException as e:
                     for idx, _, _ in group:
                         outcomes[idx] = e
@@ -360,15 +370,16 @@ def evaluate_packed(requests, pack: bool = True):
                 info["truncated"] += (
                     engine.batcher.stats["truncated_prompts"] - trunc0)
             k = 0
-            for idx, oracle, (out, missing, missing_pos) in group:
-                if missing:
-                    labels = oracle.pack_labels(pair[k:k + len(missing)])
-                    k += len(missing)
-                    out = oracle._memo_commit(out, missing, missing_pos,
-                                              labels)
-                    info["tokens"] += (oracle._tokens_of(
-                        np.asarray(missing, np.int64)) + len(missing))
-                outcomes[idx] = out
+            with tr.work("repro.oracle.commit", requests=len(group)):
+                for idx, oracle, (out, missing, missing_pos) in group:
+                    if missing:
+                        labels = oracle.pack_labels(pair[k:k + len(missing)])
+                        k += len(missing)
+                        out = oracle._memo_commit(out, missing, missing_pos,
+                                                  labels)
+                        info["tokens"] += (oracle._tokens_of(
+                            np.asarray(missing, np.int64)) + len(missing))
+                    outcomes[idx] = out
         remaining = next_pass
     return outcomes, info
 

@@ -94,7 +94,7 @@ def run_sharded_executor(emb, oracle, cfg, rng, xi, result, decided,
                      n_clusters=len(queue), executor="round",
                      shards=int(cfg.shards)) as rsp:
             t_round = monotonic()
-            with tr.span("plan", kind="plan"):
+            with tr.work("repro.csv.plan", kind="plan"):
                 plan = plan_round(queue, rng, xi, cfg, depth)
             shards = shard_clusters(plan.clusters, cfg.shards)
 
@@ -125,7 +125,7 @@ def run_sharded_executor(emb, oracle, cfg, rng, xi, result, decided,
                         osp.set(batch=int(len(flat_labels)))
                     offsets = np.cumsum([cp.n_sample for cp in shard])[:-1]
                     labels_by_cluster = np.split(flat_labels, offsets)
-                    with tr.span("vote", kind="vote", shard=s,
+                    with tr.work("repro.csv.vote", kind="vote", shard=s,
                                  n_clusters=len(shard)):
                         votes = _vote_wave(shard, labels_by_cluster, emb,
                                            cfg, lb, ub)

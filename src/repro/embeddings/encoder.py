@@ -18,6 +18,7 @@ from repro.data.tokenizer import HashTokenizer
 from repro.models import layers as L
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.lm import _init_superblock
+from repro.obs.trace import get_tracer
 
 
 def init_encoder_params(cfg: ModelConfig, key):
@@ -68,28 +69,31 @@ class EmbeddingModel:
 
     def encode(self, texts: Sequence[str], batch: int = 64) -> np.ndarray:
         """Chunked embedding: mean of per-chunk embeddings (paper §4.1)."""
+        tr = get_tracer()
         chunks: List[List[int]] = []
         owner: List[int] = []
-        for i, t in enumerate(texts):
-            ids = self.tok.encode(t)
-            for s in range(0, max(1, len(ids)), self.max_len):
-                chunks.append(ids[s:s + self.max_len])
-                owner.append(i)
+        with tr.work("repro.encoder.tokenize", texts=len(texts)):
+            for i, t in enumerate(texts):
+                ids = self.tok.encode(t)
+                for s in range(0, max(1, len(ids)), self.max_len):
+                    chunks.append(ids[s:s + self.max_len])
+                    owner.append(i)
         out = np.zeros((len(texts), self.cfg.d_model), np.float32)
         counts = np.zeros(len(texts), np.float32)
         for s in range(0, len(chunks), batch):
-            group = chunks[s:s + batch]
-            L_max = self.max_len
-            toks = np.zeros((len(group), L_max), np.int32)
-            mask = np.zeros((len(group), L_max), bool)
-            for r, c in enumerate(group):
-                toks[r, :len(c)] = c
-                mask[r, :len(c)] = True
-            emb = np.asarray(self._fn(self.params, jnp.asarray(toks),
-                                      jnp.asarray(mask)))
-            for r, o in enumerate(owner[s:s + batch]):
-                out[o] += emb[r]
-                counts[o] += 1
+            with tr.work("repro.encoder.batch"):
+                group = chunks[s:s + batch]
+                L_max = self.max_len
+                toks = np.zeros((len(group), L_max), np.int32)
+                mask = np.zeros((len(group), L_max), bool)
+                for r, c in enumerate(group):
+                    toks[r, :len(c)] = c
+                    mask[r, :len(c)] = True
+                emb = np.asarray(self._fn(self.params, jnp.asarray(toks),
+                                          jnp.asarray(mask)))
+                for r, o in enumerate(owner[s:s + batch]):
+                    out[o] += emb[r]
+                    counts[o] += 1
         out /= np.maximum(counts[:, None], 1.0)
         norms = np.linalg.norm(out, axis=1, keepdims=True)
         return out / np.maximum(norms, 1e-9)

@@ -3,15 +3,36 @@
 One query produces one span tree —
 
     query                      Query.collect (filter / join / baseline)
+      repro.query.prepare      (work) sightings, snapshots, estimates
       plan_node                PlanExecutor per executed leaf
+        repro.query.prepare    (work) session-memo lookup
         round                  CSV driver re-clustering round (or join round)
-          plan                 sample planning (RNG draws)
+          repro.csv.plan       (work, kind "plan") sample planning (RNG draws)
           oracle               oracle submit + wait (per wave)
-          vote                 segmented voting dispatch + application
+          repro.csv.vote       (work, kind "vote") voting dispatch + scatter
           partition            recluster-or-fallback tail
+            repro.csv.kmeans   (work) the k-means re-split
     dispatch_wave              QueryScheduler._run_wave (cross-query merge;
                                parented to the requesting oracle span)
+      repro.oracle.*           (work) memo_split, tokenize, commit
+      repro.engine.plan        (work) BucketBatcher.plan of the wave
       engine_tick              ServingEngine per bucketed device batch
+        repro.engine.launch    (work) host->device copies + jitted call
+        repro.engine.fetch     (work) the host blocked on the result
+
+Two sorts of span.  *Container* spans (``Tracer.span``) group a subtree
+and may hold waits: a task thread parked on its oracle future, a lane
+thread inside a wave.  They live on the collecting tracer only.  *Work*
+spans (``work()``) are leaves that wrap host computation — including a
+synchronous device call — and never a wait on a future, a condition or
+another thread; the work spans of one thread never nest or overlap (the
+collecting tracer asserts it).  Every work span also opens
+``jax.profiler.TraceAnnotation(name)`` on both tracers, so it lands on the
+profiler's clock whenever a JAX profiler session is active, beside the
+device's own events.  Waits stay off the profiler because a trace reader
+that names a device idle gap by the host events overlapping it (summed
+over threads) would otherwise name every gap after the parked threads.
+Work-span names start with ``repro.`` (docs/observability.md lists them).
 
 Span ids are stable small integers assigned in creation order under one
 lock, so a deterministic run yields a deterministic id assignment.  The
@@ -23,11 +44,13 @@ the request and passing it as ``parent=``.
 The module-global active tracer defaults to ``NULL_TRACER`` whose ``span``
 is a no-op returning a shared singleton — instrumented hot paths pay one
 attribute lookup and a no-op call when tracing is disabled, and notably
-never build per-span state.  Enable with ``set_tracer(Tracer())`` or the
-``use_tracer`` context manager.  Bit-identity: tracing only *observes*
+never build per-span state.  Its ``work`` returns the bare profiler
+annotation (under a microsecond with no profiler session; the
+attributes are never formatted).  Enable with ``set_tracer(Tracer())`` or
+the ``use_tracer`` context manager.  Bit-identity: tracing only *observes*
 (clocks + counters); it never touches an RNG stream, an oracle memo, or a
 device dispatch, so traced and untraced runs produce identical masks and
-call counts (asserted in tests/test_obs.py).
+call counts (asserted in tests/test_obs.py and tests/test_obs_profiler.py).
 """
 from __future__ import annotations
 
@@ -37,11 +60,16 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.obs.metrics import NULL_REGISTRY, MetricsRegistry
 from repro.utils.timing import monotonic
 
 _current: contextvars.ContextVar = contextvars.ContextVar(
     "repro_obs_current_span", default=None)
+# the work span open on this thread, if any (work spans never nest)
+_open_work: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_obs_open_work", default=None)
 
 
 class Span:
@@ -102,6 +130,30 @@ class _SpanCtx:
         return False
 
 
+class _WorkCtx(_SpanCtx):
+    """A work span: a collected leaf span that is also a profiler
+    annotation of the same name."""
+
+    __slots__ = ("_ann", "_work_token")
+
+    def __enter__(self) -> Span:
+        outer = _open_work.get()
+        if outer is not None:
+            raise AssertionError(
+                f"work span {self._span.name!r} opened inside work span "
+                f"{outer.name!r} on one thread: work spans are leaves")
+        self._work_token = _open_work.set(self._span)
+        self._ann = TraceAnnotation(self._span.name)
+        self._ann.__enter__()
+        return super().__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        super().__exit__(*exc)
+        self._ann.__exit__(*exc)
+        _open_work.reset(self._work_token)
+        return False
+
+
 class _NullSpan:
     """Shared do-nothing span: the disabled-tracing fast path."""
 
@@ -133,6 +185,10 @@ class NullTracer:
 
     def span(self, name, kind: str = "span", parent=None, **attrs):
         return NULL_SPAN
+
+    def work(self, name: str, kind: str = "work", **attrs):
+        """A work span on the profiler's clock alone (see module doc)."""
+        return TraceAnnotation(name)
 
     def current(self):
         return None
@@ -177,12 +233,24 @@ class Tracer:
             parent_id = parent.span_id
         else:
             parent_id = int(parent)
+        return _SpanCtx(self, self._open(name, kind, parent_id, attrs))
+
+    def work(self, name: str, kind: str = "work", **attrs) -> _WorkCtx:
+        """Open a work span: a leaf of host work, collected like any span
+        and annotated on the profiler's clock (see module doc).  It
+        parents to the thread's current span; opening one inside another
+        work span of the same thread raises ``AssertionError``."""
+        cur = _current.get()
+        return _WorkCtx(self, self._open(
+            name, kind, None if cur is None else cur.span_id, attrs))
+
+    def _open(self, name, kind, parent_id, attrs) -> Span:
         with self._lock:
             sid = self._next_id
             self._next_id += 1
             sp = Span(sid, parent_id, name, kind, attrs)
             self._spans.append(sp)
-        return _SpanCtx(self, sp)
+        return sp
 
     def current(self) -> Optional[Span]:
         """The innermost open span on THIS thread (None outside any span)."""

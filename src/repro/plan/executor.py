@@ -247,10 +247,12 @@ class PlanExecutor:
         if len(live) == 0:
             return np.zeros(self.n, dtype=bool)
         cfg = leaf.cfg if leaf.cfg is not None else self.cfg
-        hit = self.memo.lookup(leaf, cfg) if self.memo is not None else None
+        tr = get_tracer()
+        with tr.work("repro.query.prepare"):
+            hit = (self.memo.lookup(leaf, cfg) if self.memo is not None
+                   else None)
         if hit is not None:
             return self._replay_pred(leaf, cfg, live, hit)
-        tr = get_tracer()
         with tr.span("plan_node", kind="plan_node", node=leaf.name,
                      n_in=int(len(live))) as sp:
             assign = (self.table.precluster(cfg.n_clusters, cfg.seed)

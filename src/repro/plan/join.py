@@ -174,7 +174,7 @@ def sem_join(emb_left: np.ndarray, emb_right: np.ndarray, oracle,
                      n_blocks=len(blocks), executor="join") as rsp:
             t_round = monotonic()
             # ---- plan: sample still-undecided pairs in every block ----
-            with tr.span("plan", kind="plan"):
+            with tr.work("repro.join.plan", kind="plan"):
                 plans = []
                 for b in blocks:
                     undec = np.nonzero(
@@ -205,7 +205,7 @@ def sem_join(emb_left: np.ndarray, emb_right: np.ndarray, oracle,
                 decided[li, rj] = True
 
             # ---- one segmented voting dispatch over live blocks ----
-            with tr.span("vote", kind="vote", n_blocks=len(plans)):
+            with tr.work("repro.join.vote", kind="vote", n_blocks=len(plans)):
                 live = [i for i, p in enumerate(plans) if len(p[3])]
                 rest_lr = {}
                 for i in live:

@@ -94,6 +94,9 @@ class _OracleRequest:
     # captured on the task thread at park time: the explicit cross-thread
     # edge parenting the dispatch_wave span run on the FIFO lane thread
     span: object = None
+    # monotonic() when the requester parked; the wave that serves the
+    # request takes its queue wait from it
+    t_park: float = 0.0
 
 
 class _Task:
@@ -179,6 +182,11 @@ class ServiceStats:
     n_completed: int = 0
     n_failed: int = 0
     n_dispatch_ticks: int = 0    # barrier ticks that drained a batch
+    # parked oracle requests served, and their summed queue wait: from
+    # the moment a task thread parks to the start of the wave that
+    # serves it (barrier, earlier waves on the lane, lane hand-off)
+    queue_waits: int = 0
+    queue_wait_s_total: float = 0.0
 
     def metrics_view(self) -> dict:
         """Unified-name view for ``MetricsRegistry.sync_from`` (includes
@@ -190,6 +198,8 @@ class ServiceStats:
             "service.completed": self.n_completed,
             "service.failed": self.n_failed,
             "service.dispatch_ticks": self.n_dispatch_ticks,
+            "service.queue_waits": self.queue_waits,
+            "service.queue_wait_s_total": self.queue_wait_s_total,
         })
         return view
 
@@ -279,7 +289,8 @@ class QueryScheduler:
             task = _Task(self._next_index,
                          label or f"q{self._next_index}", policy)
             self._next_index += 1
-        task.query = self._instrument(task, query)
+        with get_tracer().work("repro.service.submit"):
+            task.query = self._instrument(task, query)
         ticket = QueryTicket(self, task)
         with self._cv:
             self.stats.n_submitted += 1
@@ -385,7 +396,8 @@ class QueryScheduler:
         dispatch containing this batch resolves."""
         req = _OracleRequest(task=task, oracle=oracle,
                              ids=np.asarray(ids), future=Future(),
-                             span=get_tracer().current())
+                             span=get_tracer().current(),
+                             t_park=monotonic())
         with self._cv:
             task.pending.append(req)
             self._cv.notify_all()
@@ -437,6 +449,10 @@ class QueryScheduler:
         per-oracle evaluation order stays exactly submission order."""
         tr = get_tracer()
         t0 = monotonic()
+        for r in wave:
+            self.stats.queue_wait_s_total += t0 - r.t_park
+            tr.metrics.observe("service.queue_wait_s", t0 - r.t_park)
+        self.stats.queue_waits += len(wave)
         # the wave runs on the lane thread; parent it to the first
         # requester's captured span (the cross-thread edge) and list every
         # member request's span id so all requesters stay correlated
@@ -485,6 +501,8 @@ class QueryScheduler:
             "completed": self.stats.n_completed,
             "failed": self.stats.n_failed,
             "dispatch_ticks": self.stats.n_dispatch_ticks,
+            "queue_waits": self.stats.queue_waits,
+            "queue_wait_s_total": self.stats.queue_wait_s_total,
             "mean_batch_size": self.stats.merge.mean_batch_size,
             "merge_factor": self.stats.merge.merge_factor,
         }

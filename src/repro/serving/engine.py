@@ -28,15 +28,11 @@ class ServingEngine:
         self.batcher = BucketBatcher(max_batch=max_batch, pad_id=pad_id)
         self._prefill_cache = {}
         self._decode_fn = None
-        # batch_sizes keeps only a recent window (debug visibility); the
-        # mean uses O(1) cumulative counters so a long-running server never
-        # grows without bound
         self.stats = {"prefill_tokens": 0, "decode_tokens": 0, "batches": 0,
-                      "batched_prompts": 0, "batch_sizes": [],
+                      "batched_prompts": 0,
                       # mirrored from the batcher so silent prompt-head
                       # loss is visible where serving stats are read
                       "truncated_prompts": 0, "truncated_tokens": 0}
-    _BATCH_SIZE_WINDOW = 1024
 
     @property
     def mean_batch_size(self) -> float:
@@ -90,28 +86,37 @@ class ServingEngine:
                  else token_ids.shape[-1])
         out = np.zeros((len(prompts), n_tok), np.float32)
         tr = get_tracer()
-        for idx, toks, lens in self.batcher.plan(prompts):
+        with tr.work("repro.engine.plan", prompts=len(prompts)):
+            batches = self.batcher.plan(prompts)
+        for idx, toks, lens in batches:
             with tr.span("engine_tick", kind="engine_tick", phase="prefill",
                          bucket_len=int(toks.shape[1]), batch=int(len(idx)),
                          tokens=int(lens.sum()),
                          attn_impl=self.cfg.attn_impl):
+                # launch: host->device copies and the jitted call, which
+                # returns once the step is enqueued; fetch: the host
+                # blocked until the step's result is back
                 if token_ids is None:
-                    logits = self._prefill_fn(toks.shape[1], False)(
-                        self.params, jnp.asarray(toks))
-                    last = np.asarray(logits)[np.arange(len(idx)), lens - 1]
+                    with tr.work("repro.engine.launch"):
+                        res = self._prefill_fn(toks.shape[1], False)(
+                            self.params, jnp.asarray(toks))
+                    with tr.work("repro.engine.fetch"):
+                        last = np.asarray(res)[np.arange(len(idx)),
+                                               lens - 1]
                 else:
-                    tids = (token_ids if token_ids.ndim == 1
-                            else token_ids[idx])
-                    last = np.asarray(self._select_fn(
-                        toks.shape[1], token_ids.ndim == 2)(
-                            self.params, jnp.asarray(toks),
-                            jnp.asarray(lens), jnp.asarray(tids)))
+                    with tr.work("repro.engine.launch"):
+                        tids = (token_ids if token_ids.ndim == 1
+                                else token_ids[idx])
+                        res = self._select_fn(
+                            toks.shape[1], token_ids.ndim == 2)(
+                                self.params, jnp.asarray(toks),
+                                jnp.asarray(lens), jnp.asarray(tids))
+                    with tr.work("repro.engine.fetch"):
+                        last = np.asarray(res)
             out[idx] = last
             self.stats["prefill_tokens"] += int(lens.sum())
             self.stats["batches"] += 1
             self.stats["batched_prompts"] += int(len(idx))
-            self.stats["batch_sizes"].append(int(len(idx)))
-            del self.stats["batch_sizes"][:-self._BATCH_SIZE_WINDOW]
             tr.metrics.inc("engine.prefill_tokens", int(lens.sum()))
             tr.metrics.inc("engine.ticks")
             tr.metrics.observe("engine.batch_size", int(len(idx)),
