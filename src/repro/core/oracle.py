@@ -258,13 +258,16 @@ class ModelOracle(BaseOracle):
         self.instruction = instruction
         self.yes_id = yes_id if yes_id is not None else tokenizer.token_id("yes")
         self.no_id = no_id if no_id is not None else tokenizer.token_id("no")
-        self._tok_cache: dict[int, list[int]] = {}
+        # the prompt "{instruction}\ncondition: {predicate}\ntext: {text}
+        # \nanswer:" joins its parts at whitespace, so its ids are these
+        # around the text's own (the tokenizer's content-keyed cache)
+        self._prefix = np.asarray(tokenizer.encode(
+            f"{instruction}\ncondition: {predicate}\ntext: "), np.int32)
+        self._suffix = np.asarray(tokenizer.encode("\nanswer:", bos=False),
+                                  np.int32)
 
-    def _prompt_ids(self, i: int):
-        if i not in self._tok_cache:
-            text = f"{self.instruction}\ncondition: {self.predicate}\ntext: {self.texts[i]}\nanswer:"
-            self._tok_cache[i] = self.tok.encode(text)
-        return self._tok_cache[i]
+    def _text_ids(self, ids):
+        return self.tok.text_ids([self.texts[int(i)] for i in ids])
 
     def _evaluate(self, ids):
         # narrow fast path: only the (yes, no) logit pair leaves the
@@ -278,7 +281,9 @@ class ModelOracle(BaseOracle):
             self.engine.first_token_logits(prompts, token_ids=tids))
 
     def _tokens_of(self, ids):
-        return int(sum(len(self._prompt_ids(int(i))) for i in ids))
+        parts, _ = self._text_ids(ids)
+        return (len(parts) * (len(self._prefix) + len(self._suffix))
+                + sum(len(t) for t in parts))
 
     # --- cross-oracle packing protocol (service scheduler) ---
     # Oracles sharing ``pack_engine`` can have their prompts evaluated in
@@ -290,7 +295,13 @@ class ModelOracle(BaseOracle):
         return self.engine
 
     def pack_prompts(self, ids):
-        return [self._prompt_ids(int(i)) for i in ids]
+        """The prompts of rows ``ids``, each an int32 array."""
+        parts, misses = self._text_ids(ids)
+        m = get_tracer().metrics
+        m.inc("oracle.text_ids_hits", len(parts) - misses)
+        m.inc("oracle.text_ids_misses", misses)
+        pre, suf = self._prefix, self._suffix
+        return [np.concatenate((pre, t, suf)) for t in parts]
 
     def pack_token_ids(self, n: int) -> np.ndarray:
         return np.tile(np.asarray([self.yes_id, self.no_id], np.int32),

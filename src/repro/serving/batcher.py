@@ -136,7 +136,7 @@ class BucketBatcher:
             "engine.bucket_fill": self.fill_ratio,
         }
 
-    def plan(self, prompts: Sequence[List[int]]
+    def plan(self, prompts: Sequence[Sequence[int]]
              ) -> List[Tuple[np.ndarray, np.ndarray, np.ndarray]]:
         """Group prompts -> [(orig_indices, tokens (b, L), lengths (b,))]."""
         order = np.argsort([len(p) for p in prompts], kind="stable")

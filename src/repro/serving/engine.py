@@ -70,7 +70,7 @@ class ServingEngine:
             self._prefill_cache[key] = jax.jit(f)
         return self._prefill_cache[key]
 
-    def first_token_logits(self, prompts: Sequence[List[int]],
+    def first_token_logits(self, prompts: Sequence[Sequence[int]],
                            token_ids=None) -> np.ndarray:
         """Logits at each prompt's last position.
 
