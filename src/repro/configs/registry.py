@@ -60,7 +60,7 @@ _LONG_SKIP = {
     "dbrx-132b": "pure full attention: unbounded 500k KV on all 40 layers",
     "internvl2-26b": "pure full attention on all 48 layers",
     "stablelm-12b": "pure full attention on all 40 layers",
-    "codeqwen1.5-7b": "pure full attention (MHA kv=32) on all 32 layers",
+    "codeqwen1.5-7b": "pure full attention (GQA 32/4) on all 32 layers",
     "qwen1.5-0.5b": "pure full attention (MHA kv=16) on all 24 layers",
     "whisper-base": "enc-dec with 448-token decoder design limit",
 }

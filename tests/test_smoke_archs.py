@@ -92,3 +92,28 @@ def test_family_features():
     assert gem.resolved_head_dim == 256
     assert get_config("codeqwen1.5-7b").qkv_bias
     assert get_config("whisper-base").is_encdec
+
+
+def test_codeqwen_preset_matches_benchmark_config():
+    """The preset and the chip benchmark's file describe one model: every
+    width, the KV heads, the tie, epsilon and theta; the file cuts depth
+    alone and states the published value."""
+    import json
+    import pathlib
+    path = (pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+            / "configs" / "codeqwen1.5-7b.json")
+    c = json.loads(path.read_text())
+    cfg = get_config("codeqwen1.5-7b")
+    assert c["reduced"] == ["num_hidden_layers"]
+    assert cfg.n_layers == c["published"]["num_hidden_layers"]
+    assert (cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_ff, cfg.vocab_size) == (
+        c["hidden_size"], c["num_attention_heads"], c["num_key_value_heads"],
+        c["intermediate_size"], c["vocab_size"])
+    assert cfg.resolved_head_dim == c["hidden_size"] // c["num_attention_heads"] == 128
+    assert cfg.tie_embeddings == c["tie_word_embeddings"] is False
+    assert cfg.qkv_bias == c["attention_bias"] is True
+    assert cfg.norm_eps == c["rms_norm_eps"] and cfg.rope_theta == c["rope_theta"]
+    assert cfg.dtype == c["torch_dtype"]
+    assert 7.2e9 < cfg.param_count() < 7.3e9
+    smoke = smoke_config("codeqwen1.5-7b")
+    assert smoke.n_kv_heads < smoke.n_heads and not smoke.tie_embeddings
