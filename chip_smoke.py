@@ -16,9 +16,12 @@ find it held) and starts no other process.  Phases, in order:
    synthetic ``imdb_review`` table (n=4000), with the ``qwen1.5-0.5b``
    oracle and the ``e5-large`` encoder at their published widths (random
    weights, seed 0) and a fresh session state directory.
-4. flash   — one predicate's yes/no logits again through an engine with
-   ``attn_impl="flash"`` (the Pallas prefill kernel), against the served
-   ``auto`` path on the same prompts.
+4. flash   — one predicate's yes/no logits, over the table's words cut
+   into 192 prompts of 137-1024 tokens (buckets 256, 512 and 1024),
+   through an engine with ``attn_impl="flash"`` (the Pallas prefill
+   kernel) and through the served ``auto`` engine (plain at 256, the kernel
+   from 512 up), each against an ``attn_impl="plain"`` engine on the same
+   prompts.
 
 Any failed check raises, so the process exits non-zero.  The wall times
 printed are cold (compilation included): they are not a speed metric.
@@ -42,6 +45,7 @@ from repro.core.oracle import ModelOracle  # noqa: E402
 from repro.kernels.decode_attention.kernel import decode_attention_pallas  # noqa: E402
 from repro.kernels.decode_attention.ref import decode_attention_ref  # noqa: E402
 from repro.kernels.flash_attention.kernel import flash_attention_pallas  # noqa: E402
+from repro.kernels.flash_attention.ops import flash_min_seq  # noqa: E402
 from repro.kernels.flash_attention.ref import flash_attention_ref  # noqa: E402
 from repro.kernels.kmeans.kernel import assign_clusters_pallas  # noqa: E402
 from repro.kernels.kmeans.ref import assign_clusters_ref  # noqa: E402
@@ -52,6 +56,7 @@ from repro.kernels.simvote.ref import (  # noqa: E402
 from repro.launch.runtime import enable_compile_cache, require_tpu  # noqa: E402
 from repro.launch.serve import (SERVICE_PREDICATES, build_stack,  # noqa: E402
                                 serve_concurrent)
+from repro.models.layers import attention_path  # noqa: E402
 from repro.serving import ServingEngine  # noqa: E402
 
 # Kernel-vs-ref tolerances (max abs error).  f32 kernels on unit-norm
@@ -61,9 +66,10 @@ KMEANS_DMIN_TOL = 1e-3
 KMEANS_AGREE_MIN = 0.999       # share of rows with the ref's assignment
 SIMVOTE_TOL = 5e-3
 ATTN_BF16_TOL = 2e-2
-# flash vs auto yes/no logits of the 24-layer bf16 backbone, relative to the
-# logit scale max(1, max|logit|): the two paths round attention differently
-# in every layer.  A depth-24, width-128 bf16 rehearsal differed by 2.9%.
+# flash (and auto) vs plain yes/no logits of the 24-layer bf16 backbone,
+# relative to the logit scale max(1, max|logit|): the paths round attention
+# differently in every layer.  A depth-24, width-128 bf16 rehearsal differed
+# by 2.9%.
 FLASH_LOGIT_REL_TOL = 0.1
 
 N_ROWS, N_PREDICATES = 4000, 4
@@ -192,27 +198,64 @@ def served_phase(smoke: bool = False, n: int = N_ROWS):
 
 
 def flash_phase(engine, tok, ds):
-    """Same prompts, same weights: attn_impl="flash" vs the served path."""
-    n_prompts = 128
-    oracle = ModelOracle(engine, tok, SERVICE_PREDICATES[0], ds.texts)
+    """Same prompts, same weights: attn_impl="flash" and the served engine
+    against attn_impl="plain".  The table's words are cut into prompts of
+    one engine batch a bucket, for three buckets up to twice the served
+    ``auto`` path's boundary at the backbone's head_dim: plain below it,
+    the kernel at its served tiling from it up."""
+    cfg = engine.cfg
+    boundary = flash_min_seq(cfg.resolved_head_dim)
+    # a head_dim never timed (the tiny rehearsal presets) has no boundary
+    top = 2 * (boundary or 128)
+    words = " ".join(ds.texts).split()
+    overhead = len(ModelOracle(engine, tok, SERVICE_PREDICATES[0], [""])
+                   .pack_prompts(np.arange(1))[0])
+    mb = engine.batcher.max_batch
+    texts, at = [], 0
+    for bucket in (top // 4, top // 2, top):
+        for r in range(mb):
+            # prompt lengths spread over (bucket / 2 + 8, bucket]
+            n = bucket // 2 + 8 + (bucket // 2 - 8) * (r + 1) // mb - overhead
+            text = " ".join(words[at:at + n])
+            while len(tok.encode(text, bos=False)) > n:
+                text = text.rsplit(" ", 1)[0]
+            texts.append(text)
+            at = (at + n) % (len(words) - top)
+    n_prompts = len(texts)
+    oracle = ModelOracle(engine, tok, SERVICE_PREDICATES[0], texts)
     ids = np.arange(n_prompts)
     prompts, tids = oracle.pack_prompts(ids), oracle.pack_token_ids(n_prompts)
-    flash = ServingEngine(engine.cfg.replace(attn_impl="flash"),
-                          engine.params, max_batch=engine.batcher.max_batch)
-    ref = engine.first_token_logits(prompts, token_ids=tids)
-    got = flash.first_token_logits(prompts, token_ids=tids)
-    if not (np.isfinite(ref).all() and np.isfinite(got).all()):
-        raise AssertionError("non-finite yes/no logits")
-    scale = max(1.0, float(np.abs(ref).max()))
-    err = float(np.abs(got - ref).max())
-    agree = float(np.mean((got[:, 0] > got[:, 1]) == (ref[:, 0] > ref[:, 1])))
-    print(f"[chip_smoke] flash vs {engine.cfg.attn_impl}: {n_prompts} prompts, "
-          f"max abs logit diff {err:.4g} (scale {scale:.3g}, tol "
-          f"{FLASH_LOGIT_REL_TOL:g} x scale), decision agreement "
-          f"{agree:.4f}", flush=True)
-    if not err <= FLASH_LOGIT_REL_TOL * scale:
-        raise AssertionError(f"flash logits differ by {err} > "
-                             f"{FLASH_LOGIT_REL_TOL} x {scale}")
+    paths = {e[1].shape[1]: attention_path(cfg, e[1].shape[1])
+             for e in engine.batcher.plan(prompts)}
+    print(f"[chip_smoke] flash phase: {n_prompts} prompts of "
+          f"{min(map(len, prompts))}-{max(map(len, prompts))} tokens; served "
+          f"{cfg.attn_impl} path by bucket {dict(sorted(paths.items()))}",
+          flush=True)
+    if boundary is not None and not min(paths) < boundary <= max(paths):
+        raise AssertionError(f"the buckets {sorted(paths)} do not span the "
+                             f"kernel's boundary {boundary}")
+    if jax.default_backend() == "tpu" and "flash" not in paths.values():
+        raise AssertionError("the served engine never took the kernel")
+
+    def engine_as(impl):
+        return ServingEngine(cfg.replace(attn_impl=impl), engine.params,
+                             max_batch=engine.batcher.max_batch)
+    ref = engine_as("plain").first_token_logits(prompts, token_ids=tids)
+    for name, eng in (("flash", engine_as("flash")),
+                      (f"served {cfg.attn_impl}", engine)):
+        got = eng.first_token_logits(prompts, token_ids=tids)
+        if not (np.isfinite(ref).all() and np.isfinite(got).all()):
+            raise AssertionError("non-finite yes/no logits")
+        scale = max(1.0, float(np.abs(ref).max()))
+        err = float(np.abs(got - ref).max())
+        agree = float(np.mean((got[:, 0] > got[:, 1])
+                              == (ref[:, 0] > ref[:, 1])))
+        print(f"[chip_smoke] {name} vs plain: max abs logit diff {err:.4g} "
+              f"(scale {scale:.3g}, tol {FLASH_LOGIT_REL_TOL:g} x scale), "
+              f"decision agreement {agree:.4f}", flush=True)
+        if not err <= FLASH_LOGIT_REL_TOL * scale:
+            raise AssertionError(f"{name} logits differ by {err} > "
+                                 f"{FLASH_LOGIT_REL_TOL} x {scale}")
 
 
 def main():

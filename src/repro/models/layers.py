@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distributed.api import shard_act
+from repro.distributed.api import current_rules, shard_act
 from repro.models.config import ModelConfig
 
 # --------------------------------------------------------------------------
@@ -305,16 +305,19 @@ def attention_chunked(cfg: ModelConfig, p, x, *, causal: bool, window=None,
 
 
 def attention_flash(cfg: ModelConfig, p, x, *, causal=True, window=None,
-                    positions=None):
+                    positions=None, lens=None):
     """Pallas flash-attention kernel on the prefill/forward hot path.
 
-    ``attn_impl="flash"`` runs ``repro.kernels.flash_attention`` (compiled
-    on TPU; interpret mode off-TPU, so the serving path is testable on CPU);
-    ``attn_impl="flash-ref"`` runs its jnp oracle.  Mask positions are
-    sequence-local 0..S-1 (same assumption as the chunked tri/rect
-    schedules); ``positions`` feeds RoPE only.
+    ``attn_impl="flash"`` (or ``"auto"`` where ``attention_path`` says so)
+    runs ``repro.kernels.flash_attention`` (compiled on TPU; interpret mode
+    off-TPU, so the serving path is testable on CPU); ``attn_impl=
+    "flash-ref"`` runs its jnp oracle.  Mask positions are sequence-local
+    0..S-1 (same assumption as the chunked tri/rect schedules);
+    ``positions`` feeds RoPE only.  ``lens`` (B,) true lengths of right-
+    padded rows: the kernel computes only tiles a real query needs, and
+    rows past a length come out zero.
     """
-    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.kernels.flash_attention.ops import flash_attention, flash_blocks
     B, S, _ = x.shape
     hd = cfg.resolved_head_dim
     q, k, v = _project_qkv(cfg, p, x)
@@ -327,37 +330,70 @@ def attention_flash(cfg: ModelConfig, p, x, *, causal=True, window=None,
     kh = k.transpose(0, 2, 1, 3)                         # (B, KV, S, hd)
     vh = v.transpose(0, 2, 1, 3)
     impl = "ref" if cfg.attn_impl == "flash-ref" else "pallas"
-    out = flash_attention(qh, kh, vh, causal=causal, window=window,
-                          impl=impl)
+    out = flash_attention(qh, kh, vh, lens, causal=causal, window=window,
+                          impl=impl, **flash_blocks(hd, cfg.n_heads,
+                                                    cfg.n_kv_heads))
     out = out.transpose(0, 2, 1, 3).reshape(B, S, -1)    # (B, S, H*hd)
     return out @ p["wo"]
 
 
-def attention_apply(cfg: ModelConfig, p, x, *, causal=True, window=None,
-                    positions=None, kv_x=None):
-    """Dispatch plain vs chunked vs Pallas-flash by config / seq length."""
-    S = x.shape[1]
+def attention_path(cfg: ModelConfig, S: int, *, causal=True, window=None,
+                   cross=False) -> str:
+    """The path ``attention_apply`` takes for a length-S call:
+    "plain", "chunked", "flash" (the Pallas kernel) or "flash-ref"."""
     impl = cfg.attn_impl
-    if kv_x is not None or not causal:
-        return attention_plain(cfg, p, x, causal=causal, window=window,
-                               positions=positions, kv_x=kv_x)
+    if cross or not causal:
+        return "plain"
     if impl in ("flash", "flash-ref"):
-        # the kernel tiles S by min(128, S); the engine's power-of-two
-        # buckets (>= 32) always divide.  Never swap in another path: the
-        # caller asked for the kernel.
-        if S % min(128, S):
-            raise ValueError(f"attn_impl={impl!r} needs the sequence length "
-                             f"to be a multiple of 128 or below it; got {S}")
-        return attention_flash(cfg, p, x, causal=causal, window=window,
-                               positions=positions)
+        return impl
+    # on a TPU the kernel runs from the shortest sequence at which it beat
+    # plain attention at this head_dim (ops.flash_min_seq; never for a
+    # head_dim not timed); a pallas_call cannot be auto-partitioned: never
+    # under a mesh
+    if impl == "auto" and window is None and jax.default_backend() == "tpu":
+        from repro.kernels.flash_attention.ops import flash_min_seq
+        min_seq = flash_min_seq(cfg.resolved_head_dim)
+        if (min_seq is not None and S >= min_seq and _flash_tiles_fit(cfg, S)
+                and current_rules() is None):
+            return "flash"
     if impl == "plain" or (impl == "auto" and S <= 4096 and window is None):
-        return attention_plain(cfg, p, x, causal=causal, window=window,
-                               positions=positions)
+        return "plain"
     if S % min(cfg.attn_chunk_q, S) != 0:
-        return attention_plain(cfg, p, x, causal=causal, window=window,
-                               positions=positions)
-    return attention_chunked(cfg, p, x, causal=causal, window=window,
-                             positions=positions)
+        return "plain"
+    return "chunked"
+
+
+def _flash_tiles_fit(cfg: ModelConfig, S: int) -> bool:
+    """S is a multiple of the kernel's tiles, or below them."""
+    from repro.kernels.flash_attention.ops import flash_blocks
+    b = flash_blocks(cfg.resolved_head_dim, cfg.n_heads, cfg.n_kv_heads)
+    return S % min(b["block_q"], S) == 0 and S % min(b["block_k"], S) == 0
+
+
+def attention_apply(cfg: ModelConfig, p, x, *, causal=True, window=None,
+                    positions=None, kv_x=None, lens=None):
+    """Dispatch plain vs chunked vs Pallas-flash (``attention_path``).
+
+    ``lens`` (B,) true lengths of right-padded rows, read by the flash
+    kernel alone (the other paths mask causally, which keeps padding keys
+    away from real queries)."""
+    S = x.shape[1]
+    path = attention_path(cfg, S, causal=causal, window=window,
+                          cross=kv_x is not None)
+    if path in ("flash", "flash-ref"):
+        # the engine's power-of-two buckets (>= 32) always fit the tiles.
+        # Never swap in another path: the caller asked for the kernel.
+        if not _flash_tiles_fit(cfg, S):
+            raise ValueError(f"attn_impl={cfg.attn_impl!r} needs the sequence "
+                             f"length to be a multiple of the kernel's tile "
+                             f"or below it; got {S}")
+        return attention_flash(cfg, p, x, causal=causal, window=window,
+                               positions=positions, lens=lens)
+    if path == "chunked":
+        return attention_chunked(cfg, p, x, causal=causal, window=window,
+                                 positions=positions)
+    return attention_plain(cfg, p, x, causal=causal, window=window,
+                           positions=positions, kv_x=kv_x)
 
 
 # ---------------------------------------------------------------- decode

@@ -141,13 +141,14 @@ def param_logical_axes(cfg: ModelConfig):
 
 
 def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p, h, positions,
-                 enc_out=None):
+                 enc_out=None, lens=None):
     """One layer, full-sequence mode.  Returns (h, aux)."""
     aux = jnp.float32(0.0)
     hn = L.apply_norm(cfg, p["norm"], h)
     if spec.kind == "attn":
         attn_out = L.attention_apply(cfg, p["attn"], hn, causal=True,
-                                     window=spec.window, positions=positions)
+                                     window=spec.window, positions=positions,
+                                     lens=lens)
     else:
         attn_out, _ = L.mamba_scan(cfg, p["mamba"], hn)
     h = h + attn_out
@@ -166,10 +167,12 @@ def _apply_layer(cfg: ModelConfig, spec: LayerSpec, p, h, positions,
     return h, aux
 
 
-def _superblock_fwd(cfg: ModelConfig, sb_params, h, positions, enc_out=None):
+def _superblock_fwd(cfg: ModelConfig, sb_params, h, positions, enc_out=None,
+                    lens=None):
     aux = jnp.float32(0.0)
     for i, spec in enumerate(cfg.pattern):
-        h, a = _apply_layer(cfg, spec, sb_params[f"l{i}"], h, positions, enc_out)
+        h, a = _apply_layer(cfg, spec, sb_params[f"l{i}"], h, positions,
+                            enc_out, lens)
         aux = aux + a
     return h, aux
 
@@ -184,13 +187,14 @@ def _remat(cfg: ModelConfig, fn):
 
 
 def _run_stack(cfg: ModelConfig, blocks, h, positions, enc_out=None,
-               pattern=None):
+               pattern=None, lens=None):
     pattern = pattern if pattern is not None else cfg.pattern
 
     def body(carry, sb_params):
         h, aux = carry
         cfg_local = cfg if pattern is cfg.pattern else cfg.replace(pattern=pattern)
-        h2, a = _superblock_fwd(cfg_local, sb_params, h, positions, enc_out)
+        h2, a = _superblock_fwd(cfg_local, sb_params, h, positions, enc_out,
+                                lens)
         return (h2, aux + a), None
 
     body = _remat(cfg, body)
@@ -239,8 +243,11 @@ def encode(cfg: ModelConfig, params, enc_frames):
 
 
 def forward_hidden(cfg: ModelConfig, params, tokens, prefix_embeds=None,
-                   enc_frames=None):
-    """Full-sequence forward up to the final hidden states -> (h, aux)."""
+                   enc_frames=None, lens=None):
+    """Full-sequence forward up to the final hidden states -> (h, aux).
+
+    ``lens`` (B,) true lengths of right-padded rows, for attention that
+    can skip padding (the flash kernel); None: every row is full."""
     h = _embed_tokens(cfg, params, tokens)
     if prefix_embeds is not None:
         h = jnp.concatenate([prefix_embeds.astype(h.dtype), h], axis=1)
@@ -251,7 +258,8 @@ def forward_hidden(cfg: ModelConfig, params, tokens, prefix_embeds=None,
     h = shard_act(h, ("batch", None, None))
     positions = jnp.arange(S)[None, :]
     enc_out = encode(cfg, params, enc_frames) if cfg.is_encdec else None
-    return _run_stack(cfg, params["blocks"], h, positions, enc_out)
+    return _run_stack(cfg, params["blocks"], h, positions, enc_out,
+                      lens=lens)
 
 
 def first_logits_select(cfg: ModelConfig, params, tokens, lens, token_ids):
@@ -263,7 +271,7 @@ def first_logits_select(cfg: ModelConfig, params, tokens, lens, token_ids):
     materialization is skipped.  ``token_ids`` is (T,) shared across the
     batch or (B, T) per prompt; ``lens`` (B,) true prompt lengths.
     """
-    h, _ = forward_hidden(cfg, params, tokens)
+    h, _ = forward_hidden(cfg, params, tokens, lens=lens)
     hl = h[jnp.arange(h.shape[0]), lens - 1]           # (B, D)
     hl = L.apply_norm(cfg, params["final_norm"], hl)
     table = params["lm_head"]["w"] if not cfg.tie_embeddings else params["embed"]["table"]

@@ -14,7 +14,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import lm
+from repro.kernels.flash_attention.kernel import tile_counts
+from repro.kernels.flash_attention.ops import flash_blocks
+from repro.models import layers, lm
 from repro.models.config import ModelConfig
 from repro.obs.trace import get_tracer
 from repro.serving.batcher import BucketBatcher
@@ -89,10 +91,11 @@ class ServingEngine:
         with tr.work("repro.engine.plan", prompts=len(prompts)):
             batches = self.batcher.plan(prompts)
         for idx, toks, lens in batches:
+            path = layers.attention_path(self.cfg, toks.shape[1])
             with tr.span("engine_tick", kind="engine_tick", phase="prefill",
                          bucket_len=int(toks.shape[1]), batch=int(len(idx)),
                          tokens=int(lens.sum()),
-                         attn_impl=self.cfg.attn_impl):
+                         attn_impl=self.cfg.attn_impl, attn_path=path):
                 # launch: host->device copies and the jitted call, which
                 # returns once the step is enqueued; fetch: the host
                 # blocked until the step's result is back
@@ -100,6 +103,10 @@ class ServingEngine:
                     with tr.work("repro.engine.launch"):
                         res = self._prefill_fn(toks.shape[1], False)(
                             self.params, jnp.asarray(toks))
+                    if tr.enabled:   # host accounting while the step runs
+                        self._count_attn_tiles(tr, path, toks.shape[1],
+                                               np.full(len(idx),
+                                                       toks.shape[1]))
                     with tr.work("repro.engine.fetch"):
                         last = np.asarray(res)[np.arange(len(idx)),
                                                lens - 1]
@@ -111,6 +118,8 @@ class ServingEngine:
                             toks.shape[1], token_ids.ndim == 2)(
                                 self.params, jnp.asarray(toks),
                                 jnp.asarray(lens), jnp.asarray(tids))
+                    if tr.enabled:
+                        self._count_attn_tiles(tr, path, toks.shape[1], lens)
                     with tr.work("repro.engine.fetch"):
                         last = np.asarray(res)
             out[idx] = last
@@ -126,6 +135,20 @@ class ServingEngine:
         for k in ("truncated_prompts", "truncated_tokens"):
             self.stats[k] = self.batcher.stats[k]
         return out
+
+    def _count_attn_tiles(self, tr, path: str, L: int, lens) -> None:
+        """``engine.attn_tiles_run`` / ``engine.attn_tiles_full``: score
+        tiles (per head and layer, in the flash kernel's tiling) that this
+        step computes, against the full L x L grid of its rows; their ratio
+        is the share of plain attention's work the step still does."""
+        cfg = self.cfg
+        blocks = flash_blocks(cfg.resolved_head_dim, cfg.n_heads,
+                              cfg.n_kv_heads)
+        run, full = tile_counts(lens, L, block_q=blocks["block_q"],
+                                block_k=blocks["block_k"])
+        tr.metrics.inc("engine.attn_tiles_run", run if path == "flash"
+                       else full)
+        tr.metrics.inc("engine.attn_tiles_full", full)
 
     # --------------------------------------------------------------- decode
     def _decode(self, params, cache, tokens, pos):

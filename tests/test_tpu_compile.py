@@ -30,6 +30,7 @@ from repro.kernels.kmeans.kernel import assign_clusters_pallas
 from repro.kernels.simvote.kernel import (simvote_scores_pallas,
                                           simvote_scores_segmented_pallas)
 from repro.models import lm
+from repro.kernels.flash_attention.ops import flash_min_seq
 
 V5E_HBM_BYTES = 16 * 2**30
 
@@ -131,19 +132,41 @@ def test_decode_attention_kernel_compiles(one_chip, cache_len):
     assert _has_kernel(c)
 
 
+def _select_step(cfg, sharding, batch, seq):
+    params = jax.tree_util.tree_map(
+        lambda a: _spec(sharding, a.shape, a.dtype), lm.abstract_params(cfg))
+    return _compile(lambda p, t, n, ids: lm.first_logits_select(cfg, p, t, n,
+                                                                ids),
+                    params, _spec(sharding, (batch, seq), jnp.int32),
+                    _spec(sharding, (batch,), jnp.int32),
+                    _spec(sharding, (batch, 2), jnp.int32))
+
+
 @pytest.mark.parametrize("attn_impl", ["auto", "flash"])
 def test_oracle_select_step_compiles_full_width(one_chip, on_tpu_dispatch,
                                                 attn_impl):
-    """The engine's yes/no step at full qwen1.5-0.5b width, B=64, L=256."""
+    """The engine's yes/no step at full qwen1.5-0.5b width, B=64: "flash"
+    runs the kernel at every length, "auto" from head_dim 64's boundary up
+    (the kernel at and above the boundary, none below it)."""
     cfg = get_config("qwen1.5-0.5b").replace(attn_impl=attn_impl)
-    params = jax.tree_util.tree_map(
-        lambda a: _spec(one_chip, a.shape, a.dtype), lm.abstract_params(cfg))
-    c = _compile(lambda p, t, n, ids: lm.first_logits_select(cfg, p, t, n,
-                                                             ids),
-                 params, _spec(one_chip, (64, 256), jnp.int32),
-                 _spec(one_chip, (64,), jnp.int32),
-                 _spec(one_chip, (64, 2), jnp.int32))
-    assert _has_kernel(c) == (attn_impl == "flash")
+    boundary = flash_min_seq(cfg.resolved_head_dim)
+    for seq in (boundary // 2, boundary):
+        c = _select_step(cfg, one_chip, 64, seq)
+        assert _has_kernel(c) == (attn_impl == "flash"
+                                  or seq >= boundary), seq
+        assert _device_bytes(c) < V5E_HBM_BYTES
+
+
+@pytest.mark.parametrize("seq", [128, 256, 512])
+def test_oracle_select_step_compiles_7b_shape(one_chip, on_tpu_dispatch,
+                                              seq):
+    """codeqwen1.5-7b's step as its cell serves it (16 of 32 layers, GQA
+    32/4 at head_dim 128, B=16, row lengths to the kernel) under "auto":
+    the kernel from head_dim 128's boundary (256) up, none below it."""
+    cfg = get_config("codeqwen1.5-7b").replace(n_layers=16)
+    assert flash_min_seq(cfg.resolved_head_dim) == 256
+    c = _select_step(cfg, one_chip, 16, seq)
+    assert _has_kernel(c) == (seq >= 256)
     assert _device_bytes(c) < V5E_HBM_BYTES
 
 
